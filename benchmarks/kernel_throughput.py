@@ -1,5 +1,5 @@
-"""PPA kernel-layer throughput on this host (CPU): jnp ref path vs Pallas
-interpret path vs numpy golden, plus the model-level activation ops and the
+"""PPA kernel-layer throughput on this host: jnp ref path vs the Pallas
+kernel (interpreted where the platform is the CPU) vs numpy golden, plus the model-level activation ops and the
 fused float->PPA->float pipeline vs its unfused composition (Table-1
 sigmoid config).  Absolute numbers are CPU-bound; the deliverable is the
 relative cost and the bit-exactness cross-check at size.
@@ -44,10 +44,10 @@ def main(smoke: bool = False) -> None:
     emit("kernel/ref_jit", us, melems_per_s=f"{n / us:.1f}")
 
     pal = jax.jit(lambda v: ppa_eval_2d(v, tc.starts, tc.coefs, tc.plan,
-                                        block=(8, 128), interpret=True))
+                                        block=(8, 128)))
     us_p = timeit(lambda: pal(x).block_until_ready(), repeats=reps_slow)
-    emit("kernel/pallas_interpret", us_p, melems_per_s=f"{n / us_p:.1f}",
-         note="interpret-mode (CPU validation; compiled on real TPU)")
+    emit("kernel/pallas", us_p, melems_per_s=f"{n / us_p:.1f}",
+         platform=jax.default_backend())
 
     y_ref = np.asarray(ref(x))
     y_pal = np.asarray(pal(x))
@@ -66,16 +66,16 @@ def main(smoke: bool = False) -> None:
          note="jnp quantize/dequantize around the ref datapath")
 
     fused = jax.jit(
-        lambda v: ppa_apply(tc, v, backend="pallas_fused_interpret"))
+        lambda v: ppa_apply(tc, v, backend="pallas_fused"))
     us_f = timeit(lambda: fused(xf).block_until_ready(), repeats=reps_slow)
     emit("kernel/ppa_apply_fused", us_f, melems_per_s=f"{n / us_f:.1f}",
          vs_unfused=f"{us_a / us_f:.2f}x",
-         note="one pallas_call: quantize->PPA->dequantize (interpret mode)")
+         note="one pallas_call: quantize->PPA->dequantize")
 
     gate_u = jax.jit(lambda v: ppa_gate(tc, v))
     us_gu = timeit(lambda: gate_u(xf).block_until_ready(), repeats=reps)
     gate_f = jax.jit(
-        lambda v: ppa_gate(tc, v, backend="pallas_fused_interpret"))
+        lambda v: ppa_gate(tc, v, backend="pallas_fused"))
     us_gf = timeit(lambda: gate_f(xf).block_until_ready(), repeats=reps_slow)
     emit("kernel/ppa_gate_fused", us_gf, unfused_us=f"{us_gu:.2f}",
          vs_unfused=f"{us_gu / us_gf:.2f}x",

@@ -26,8 +26,8 @@ def main():
                     choices=["exact", "ppa", "ppa8"])
     ap.add_argument("--act-backend", default=None,
                     help="PPA execution backend override, e.g. "
-                         "pallas_fused (TPU) / pallas_fused_interpret (CPU);"
-                         " see repro.kernels.available_backends()")
+                         "pallas_fused (interpreted where the platform is "
+                         "the CPU); see repro.kernels.available_backends()")
     args = ap.parse_args()
 
     cfg = get_smoke_config(args.arch).replace(act_impl=args.act_impl)

@@ -13,10 +13,15 @@ the platform cannot run a process pool (restricted sandboxes, missing
 semaphores, workers killed), the driver degrades to in-process serial
 compilation; a *job's own* exception (e.g. an infeasible MAE_t) always
 propagates.
+
+The pool is a ``spawn`` pool whose workers are pinned to the CPU and the
+numpy search backend: the parent may already hold an accelerator, which
+belongs to one process, and a forked child would inherit its runtime.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from typing import Dict, List, Optional, Sequence
 
@@ -36,6 +41,13 @@ def _compile_job_json(job: CompileJob) -> str:
     reaches them) — the mid-compile crash site."""
     failpoint("compile.job", key=job.key())
     return job.compile().to_json()
+
+
+def _pin_worker() -> None:
+    """Pool initializer: this worker never takes an accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 def compile_batch(jobs: Sequence[CompileJob], *,
@@ -63,12 +75,17 @@ def compile_batch(jobs: Sequence[CompileJob], *,
         processes = min(os.cpu_count() or 1, len(uniq))
     results: Optional[List[str]] = None
     if processes > 1:
+        import multiprocessing
         from concurrent.futures import ProcessPoolExecutor
         from concurrent.futures.process import BrokenProcessPool
+        pinned = [dataclasses.replace(jobs[i], search_backend="numpy")
+                  for i in uniq]
         try:
-            with ProcessPoolExecutor(max_workers=processes) as ex:
-                results = list(ex.map(_compile_job_json,
-                                      [jobs[i] for i in uniq]))
+            with ProcessPoolExecutor(
+                    max_workers=processes,
+                    mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_pin_worker) as ex:
+                results = list(ex.map(_compile_job_json, pinned))
         except (OSError, PermissionError, BrokenProcessPool):
             results = None  # pool unavailable here; fall back to serial
     if results is None:

@@ -498,11 +498,8 @@ class TableStore:
         are asserted identical by the tune-smoke CI tier."""
         if not self.persist:
             return job
-        try:
-            from repro.tune import activate, resolve_tuned
-            tuned = resolve_tuned(self.root)
-        except Exception:
-            return job
+        from repro.tune import activate, resolve_tuned
+        tuned = resolve_tuned(self.root)
         if tuned is None:
             return job
         activate(tuned)     # floors + default block (idempotent)

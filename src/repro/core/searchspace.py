@@ -11,7 +11,7 @@ small backend contract so the *same* scan can execute eagerly on numpy
   * :class:`NumpySearchBackend` — the golden model.  Bit-identical to the
     seed ``eval_block`` (same ops through :func:`~.datapath.horner_body`).
   * :class:`JaxSearchBackend` — the same code path traced under jnp with
-    x64 enabled (int64/float64, scoped via ``jax.experimental.enable_x64``
+    x64 enabled (int64/float64, scoped via ``jax.enable_x64(True)``
     so the rest of the process keeps jax's default dtypes).  The window
     grid is staged device-resident once per segment context; candidate
     blocks and grids are padded to power-of-two buckets (edge replication,
@@ -207,18 +207,17 @@ def jax_backend_available() -> Tuple[bool, str]:
     """(ok, reason) — whether the jitted x64 backend can run here."""
     global _JAX_STATE
     if _JAX_STATE is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-            with enable_x64():
-                probe = jnp.asarray(np.arange(2, dtype=np.int64))
-                if str(probe.dtype) != "int64":
-                    raise RuntimeError(
-                        f"x64 scope yielded {probe.dtype}, not int64")
+        # jax is a hard dependency: an import or API error here is a broken
+        # install and propagates; only a device without int64 says "no".
+        import jax
+        import jax.numpy as jnp
+        with jax.enable_x64(True):
+            probe = jnp.asarray(np.arange(2, dtype=np.int64))
+        if str(probe.dtype) != "int64":
+            _JAX_STATE = (False, f"x64 scope yielded {probe.dtype}, "
+                                 "not int64")
+        else:
             _JAX_STATE = (True, f"jax {jax.__version__}")
-        except Exception as e:          # missing jax, no x64, no device...
-            _JAX_STATE = (False, f"{type(e).__name__}: {e}")
     return _JAX_STATE
 
 
@@ -311,9 +310,9 @@ class JaxSearchBackend(SearchBackend):
         half of the contract."""
         dev = ctx.cache.get(("jax", gp))
         if dev is None:
+            import jax
             import jax.numpy as jnp
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with jax.enable_x64(True):
                 dev = (jnp.asarray(_pad_edge(ctx.x_int, gp)),
                        jnp.asarray(_pad_edge(ctx.f_vals, gp)),
                        jnp.asarray(_pad_edge(ctx.f_q, gp)))
@@ -321,7 +320,7 @@ class JaxSearchBackend(SearchBackend):
         return dev
 
     def eval_block(self, ctx, a_list):
-        from jax.experimental import enable_x64
+        import jax
         import jax.numpy as jnp
         k = int(a_list[0].size)
         kp = _bucket(k, self.K_FLOOR)
@@ -329,7 +328,7 @@ class JaxSearchBackend(SearchBackend):
         a_stack = np.stack([_pad_edge(np.asarray(a, dtype=np.int64), kp)
                             for a in a_list])
         fn = _jitted_block_fn(ctx.plan, ctx.cfg.w_b, ctx.flatten_b, None)
-        with enable_x64():
+        with jax.enable_x64(True):
             x, f, f_q = self._grid(ctx, gp)
             mae, b_int, mae0 = fn(jnp.asarray(a_stack),
                                   jnp.asarray(np.int64(ctx.b_fixed)),
@@ -352,7 +351,7 @@ class JaxSearchBackend(SearchBackend):
         if len(blocks) == 1:
             ctx, a_list = blocks[0]
             return [self.eval_block(ctx, a_list)]
-        from jax.experimental import enable_x64
+        import jax
         import jax.numpy as jnp
         plan = blocks[0][0].plan
         w_b = blocks[0][0].cfg.w_b
@@ -375,7 +374,7 @@ class JaxSearchBackend(SearchBackend):
         b_fixed = np.array([blocks[i][0].b_fixed for i in idx],
                            dtype=np.int64)
         fn = _jitted_block_fn(plan, w_b, flatten_b, "windows")
-        with enable_x64():
+        with jax.enable_x64(True):
             mae, b_int, mae0 = fn(jnp.asarray(a), jnp.asarray(b_fixed),
                                   jnp.asarray(x), jnp.asarray(f),
                                   jnp.asarray(f_q))
@@ -403,7 +402,7 @@ class JaxSearchBackend(SearchBackend):
         """
         if len(blocks) <= 1:
             return super().eval_block_batch(ctx, blocks)
-        from jax.experimental import enable_x64
+        import jax
         import jax.numpy as jnp
         gp = _bucket(ctx.num, self.G_FLOOR)
         fn = _jitted_block_fn(ctx.plan, ctx.cfg.w_b, ctx.flatten_b,
@@ -421,7 +420,7 @@ class JaxSearchBackend(SearchBackend):
                 a = np.stack([np.stack(
                     [_pad_edge(np.asarray(ai, dtype=np.int64), kp_max)
                      for ai in group[i]]) for i in idx])
-                with enable_x64():
+                with jax.enable_x64(True):
                     x, f, f_q = self._grid(ctx, gp)
                     mae, b_int, mae0 = fn(
                         jnp.asarray(a), jnp.asarray(np.int64(ctx.b_fixed)),

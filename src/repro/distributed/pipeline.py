@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
@@ -105,7 +104,7 @@ def pipeline_apply(
         return out_buf.reshape(h_all.shape)
 
     pspec = jax.tree_util.tree_map(lambda _: PS(axis), stack_params)
-    return shard_map(
+    return jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(pspec, PS()),      # params: layers sharded; h replicated
         out_specs=PS(),

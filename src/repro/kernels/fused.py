@@ -31,7 +31,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan
 
-from .body import ppa_eval_block
+from .body import ppa_eval_block, resolve_interpret, smem_operands
 from .ppa import DEFAULT_BLOCK, default_block, pad_to_tiles
 
 __all__ = ["ppa_fused_2d", "ppa_fused_apply", "fused_kernel_statics"]
@@ -62,7 +62,7 @@ def _fused_kernel(x_ref, starts_ref, coef_ref, out_ref, *,
 
     y_int = ppa_eval_block(x_int, starts_ref, coef_ref, plan,
                            num_segments=num_segments)
-    y = y_int.astype(jnp.float32) / float(1 << plan.w_out)
+    y = y_int.astype(jnp.float32) * (1.0 / (1 << plan.w_out))
 
     # saturation outside the fitted interval
     if sat_identity:
@@ -99,7 +99,7 @@ def ppa_fused_2d(
     *,
     gate: bool = False,
     block: Tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     **statics,
 ) -> jax.Array:
     """Run the fused pipeline on a 2D float32 array (pre-padded to tiles).
@@ -107,29 +107,23 @@ def ppa_fused_2d(
     ``statics`` are the scalars from :func:`fused_kernel_statics`.
     """
     m, n = xf.shape
-    s = starts.shape[0]
-    order = statics["plan"].order
     grid = (m // block[0], n // block[1])
     kernel = functools.partial(_fused_kernel, gate=gate, **statics)
+    table_specs, table_args = smem_operands(starts, coefs)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(block, lambda i, j: (i, j)),
-            pl.BlockSpec((s,), lambda i, j: (0,)),
-            pl.BlockSpec((s, order + 1), lambda i, j: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec(block, lambda i, j: (i, j)), *table_specs],
         out_specs=pl.BlockSpec(block, lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(xf.astype(jnp.float32), starts.astype(jnp.int32),
-      coefs.astype(jnp.int32))
+        interpret=resolve_interpret(interpret),
+    )(xf.astype(jnp.float32), *table_args)
 
 
 def ppa_fused_apply(tc, xf: jax.Array, *, gate: bool = False,
                     block: "Tuple[int, int] | None" = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Any-shape adapter: flatten + pad to the tile grid, run the fused
     kernel, unpad.  float32 in, float32 out.
 

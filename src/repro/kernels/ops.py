@@ -28,18 +28,18 @@ Pieces:
 Execution path selection goes through the **backend registry**
 (:func:`register_backend` / :func:`available_backends`):
 
-  ref                     pure jnp searchsorted + shared Horner body
-                          (paper-faithful; runs everywhere) — the default
-  lut_value               one gather; the PPA compile is the LUT generator
-  lut_index               gather the segment index, keep the Horner datapath
-  pallas[_interpret]      the tiled int32 TPU kernel (kernels/ppa.py)
-  pallas_fused[_interpret] the fused float->PPA->float kernel
-                          (kernels/fused.py): quantize, symmetry, segment
-                          select, Horner, dequantize, saturation and the
-                          optional self-gating in ONE pallas_call
+  ref           pure jnp searchsorted + shared Horner body
+                (paper-faithful; runs everywhere) — the default
+  lut_value     one gather; the PPA compile is the LUT generator
+  lut_index     gather the segment index, keep the Horner datapath
+  pallas        the tiled int32 TPU kernel (kernels/ppa.py)
+  pallas_fused  the fused float->PPA->float kernel (kernels/fused.py):
+                quantize, symmetry, segment select, Horner, dequantize,
+                saturation and the optional self-gating in ONE pallas_call
 
-``*_interpret`` variants run the same kernel in interpret mode (CPU
-validation).  All backends are bit-identical; tests assert exact equality.
+The Pallas backends compile for the TPU, and run the same kernel in
+interpret mode where the platform is the CPU.  All backends are
+bit-identical; tests assert exact equality.
 """
 
 from __future__ import annotations
@@ -214,21 +214,18 @@ def _eval_lut_index(tc: TableConsts, x_int: jax.Array) -> jax.Array:
     return horner_int(tc.coefs.astype(jnp.int32)[idx], x_int, tc.plan)
 
 
-def _eval_pallas(tc: TableConsts, x_int: jax.Array, *,
-                 interpret: bool) -> jax.Array:
+def _eval_pallas(tc: TableConsts, x_int: jax.Array) -> jax.Array:
     shape = x_int.shape
     flat = x_int.reshape(-1)
     n = flat.shape[0]
     bm, bn = default_block()
     x2, blk = pad_to_tiles(flat, bm, bn)
-    out = ppa_eval_2d(x2, tc.starts, tc.coefs, tc.plan, block=blk,
-                      interpret=interpret)
+    out = ppa_eval_2d(x2, tc.starts, tc.coefs, tc.plan, block=blk)
     return out.reshape(-1)[:n].reshape(shape)
 
 
-def _apply_fused(tc: TableConsts, xf: jax.Array, gate: bool, *,
-                 interpret: bool) -> jax.Array:
-    return ppa_fused_apply(tc, xf, gate=gate, interpret=interpret)
+def _apply_fused(tc: TableConsts, xf: jax.Array, gate: bool) -> jax.Array:
+    return ppa_fused_apply(tc, xf, gate=gate)
 
 
 register_backend("ref", eval_int=_eval_ref,
@@ -237,18 +234,10 @@ register_backend("lut_value", eval_int=_eval_lut_value,
                  doc="one gather over the pre-tabulated datapath output")
 register_backend("lut_index", eval_int=_eval_lut_index,
                  doc="gathered segment index + Horner datapath")
-register_backend("pallas",
-                 eval_int=functools.partial(_eval_pallas, interpret=False),
-                 doc="tiled int32 Pallas kernel (TPU)")
-register_backend("pallas_interpret",
-                 eval_int=functools.partial(_eval_pallas, interpret=True),
-                 doc="tiled int32 Pallas kernel, interpret mode (CPU)")
-register_backend("pallas_fused",
-                 apply=functools.partial(_apply_fused, interpret=False),
-                 doc="fused float->PPA->float Pallas kernel (TPU)")
-register_backend("pallas_fused_interpret",
-                 apply=functools.partial(_apply_fused, interpret=True),
-                 doc="fused float->PPA->float kernel, interpret mode (CPU)")
+register_backend("pallas", eval_int=_eval_pallas,
+                 doc="tiled int32 Pallas kernel")
+register_backend("pallas_fused", apply=_apply_fused,
+                 doc="fused float->PPA->float Pallas kernel")
 
 
 # --------------------------------------------------------------------------
@@ -285,7 +274,9 @@ def _apply_f32(tc: TableConsts, x0: jax.Array, backend: str,
     x_int_c = jnp.clip(x_int, tc.lo, tc.hi - 1)
 
     y_int = be.eval_int(tc, x_int_c)
-    y = y_int.astype(jnp.float32) / float(1 << tc.w_out)
+    # dequantize by an exact power-of-two multiply: a float division may
+    # lower to an approximate reciprocal inside a TPU kernel
+    y = y_int.astype(jnp.float32) * (1.0 / (1 << tc.w_out))
 
     if tc.sat_identity:
         y = jnp.where(oob_hi, xf, y)
@@ -302,12 +293,26 @@ def _apply_f32(tc: TableConsts, x0: jax.Array, backend: str,
     return y
 
 
+def _bounded(tc: TableConsts, x: jax.Array, backend: str,
+             gate: bool) -> jax.Array:
+    """``_apply_f32`` between two optimization barriers.
+
+    A Pallas kernel is opaque to XLA, while the jnp backends fuse into
+    their producer and consumer.  On the TPU a matmul or a reduction fused
+    with different neighbours can be tiled differently and sum in another
+    order, so the program around the activation would round differently
+    per backend.  The barriers make every backend meet the rest of the
+    program at the same materialized input and output."""
+    x = jax.lax.optimization_barrier(x)
+    y = _apply_f32(tc, x.astype(jnp.float32), backend, gate).astype(x.dtype)
+    return jax.lax.optimization_barrier(y)
+
+
 def ppa_apply(tc: TableConsts, x: jax.Array, *, backend: str = "ref"
               ) -> jax.Array:
     """Full deployment path: float in -> fixed-point PPA datapath -> float
     out, through the selected backend."""
-    return _apply_f32(tc, x.astype(jnp.float32), backend,
-                      False).astype(x.dtype)
+    return _bounded(tc, x, backend, False)
 
 
 def ppa_gate(tc: TableConsts, x: jax.Array, *, backend: str = "ref"
@@ -316,8 +321,7 @@ def ppa_gate(tc: TableConsts, x: jax.Array, *, backend: str = "ref"
     from a gelu_inner table).  The gating multiply runs in float32 before
     the output cast on every backend — inside the kernel on the fused one —
     so all backends stay bit-identical."""
-    return _apply_f32(tc, x.astype(jnp.float32), backend,
-                      True).astype(x.dtype)
+    return _bounded(tc, x, backend, True)
 
 
 def _exact(naf: str, x: jax.Array) -> jax.Array:

@@ -8,8 +8,9 @@ nothing on its own.
 Block layout: x is tiled (block_m, 128) int32 — the minor dimension matches
 the 128-lane VPU; block_m=256 keeps in+out VMEM traffic at 256 KiB/block,
 far below the ~16 MiB v5e VMEM budget, leaving room for double buffering.
-The (S, n+1) int32 coefficient ROM rides in VMEM next to the block
-(< 2 KiB for every paper config).
+The segment starts and the flattened (S * (n+1),) int32 coefficient ROM sit
+whole in SMEM (< 8 KiB for every deployment table): the comparator sweep
+reads them one scalar at a time.
 """
 
 from __future__ import annotations
@@ -24,7 +25,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan, FWLConfig
 
-from .body import ppa_eval_block
+from .body import ppa_eval_block, resolve_interpret, smem_operands
 
 if TYPE_CHECKING:  # avoid a module-level kernels -> core.schemes import edge
     from repro.core.schemes import PPATable
@@ -88,7 +89,7 @@ def ppa_eval_2d(
     plan: PlanLike,
     *,
     block: Tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Evaluate the PPA datapath on a 2D int32 array (pre-padded).
 
@@ -98,28 +99,24 @@ def ppa_eval_2d(
       starts: (S,) int32 sorted segment starts (FWL plan.w_in).
       coefs: (S, n+1) int32 — columns a_1..a_n then b.
       plan: the DatapathPlan (or the FWLConfig to derive it from).
-      interpret: run the kernel body in interpret mode (CPU validation);
-        pass False on real TPU.
+      interpret: None derives it from the platform (see
+        :func:`~repro.kernels.body.resolve_interpret`).
     """
     plan = as_plan(plan)
     m, n = x_int.shape
     s = starts.shape[0]
     grid = (m // block[0], n // block[1])
     kernel = functools.partial(_ppa_kernel, plan=plan, num_segments=s)
+    table_specs, table_args = smem_operands(starts, coefs)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(block, lambda i, j: (i, j)),
-            pl.BlockSpec((s,), lambda i, j: (0,)),
-            pl.BlockSpec((s, plan.order + 1), lambda i, j: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec(block, lambda i, j: (i, j)), *table_specs],
         out_specs=pl.BlockSpec(block, lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
-    )(x_int.astype(jnp.int32), starts.astype(jnp.int32),
-      coefs.astype(jnp.int32))
+        interpret=resolve_interpret(interpret),
+    )(x_int.astype(jnp.int32), *table_args)
 
 
 def pad_to_tiles(flat: jax.Array, block_m: int, block_n: int
@@ -153,7 +150,7 @@ def ppa_eval_table(
     x_int: jax.Array,
     *,
     block: Tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Evaluate a :class:`PPATable` artifact on integer inputs of any shape.
 

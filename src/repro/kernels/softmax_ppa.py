@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan
 
-from .body import ppa_eval_block
+from .body import ppa_eval_block, resolve_interpret, smem_operands
 from .ops import TableConsts
 
 __all__ = ["softmax_ppa_2d"]
@@ -60,7 +61,7 @@ def _softmax_kernel(x_ref, starts_ref, coef_ref, out_ref, *,
     y_int = ppa_eval_block(f_int, starts_ref, coef_ref, plan,
                            num_segments=num_segments)
 
-    e = y_int.astype(jnp.float32) / np.float32(1 << plan.w_out)
+    e = y_int.astype(jnp.float32) * np.float32(1.0 / (1 << plan.w_out))
     e = e * jnp.exp2(k)                                    # exact scale
     if valid_n < n:
         e = jnp.where(col < valid_n, e, 0.0)
@@ -69,7 +70,7 @@ def _softmax_kernel(x_ref, starts_ref, coef_ref, out_ref, *,
 
 
 def softmax_ppa_2d(x: jax.Array, tc: TableConsts, *, block_m: int = 8,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Row softmax over the last axis of a 2D float array via PPA exp2."""
     assert tc.naf == "exp2_frac", tc.naf
     m, n = x.shape
@@ -82,17 +83,14 @@ def softmax_ppa_2d(x: jax.Array, tc: TableConsts, *, block_m: int = 8,
     kernel = functools.partial(_softmax_kernel, plan=plan,
                                num_segments=tc.num_segments, valid_n=n)
 
-    s = tc.num_segments
+    table_specs, table_args = smem_operands(tc.starts, tc.coefs)
     out = pl.pallas_call(
         kernel,
         grid=(mp // block_m,),
-        in_specs=[
-            pl.BlockSpec((block_m, np_), lambda i: (i, 0)),
-            pl.BlockSpec((s,), lambda i: (0,)),
-            pl.BlockSpec((s, plan.order + 1), lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((block_m, np_), lambda i: (i, 0)),
+                  *table_specs],
         out_specs=pl.BlockSpec((block_m, np_), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
-    )(xp.astype(jnp.float32), tc.starts, tc.coefs)
+        interpret=resolve_interpret(interpret),
+    )(xp.astype(jnp.float32), *table_args)
     return out[:m, :n].astype(x.dtype)

@@ -1,11 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first statements in this module — jax
-locks the device count at first backend init, and the production meshes
-need 512 host-platform placeholder devices.  Nothing here allocates a
+The three lines above MUST stay the first statements in this module — jax
+locks the platform and device count at first backend init, and the
+production meshes need 512 host-platform placeholder devices (on a TPU
+host the dry run must not take the chip).  Nothing here allocates a
 buffer: parameters, optimizer state, caches and batches are all
 ShapeDtypeStructs; ``.lower().compile()`` exercises the full GSPMD
 partitioner + XLA pipeline, and the compiled artifact yields

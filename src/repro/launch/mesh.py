@@ -25,7 +25,19 @@ TPU_PERF_FLAGS = (
     "--xla_enable_async_collective_permute=true"
 )
 
-__all__ = ["make_production_mesh", "mesh_desc", "TPU_PERF_FLAGS"]
+__all__ = ["make_production_mesh", "make_local_mesh", "mesh_desc",
+           "TPU_PERF_FLAGS"]
+
+
+def _auto_mesh(shape, axes, devices):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    through ops (an ``Explicit`` axis makes ``jnp.take`` on a vocab-sharded
+    table a sharding-type error)."""
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -44,7 +56,19 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before the first jax import (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
+
+
+def make_local_mesh(n_model: int = 4):
+    """(data=1, model=n_model) tensor-parallel mesh over the first
+    ``n_model`` local devices — one host's chips, one process."""
+    import jax
+
+    devices = jax.local_devices()
+    if len(devices) < n_model:
+        raise RuntimeError(f"need {n_model} local devices for a "
+                           f"(1, {n_model}) mesh, have {len(devices)}")
+    return _auto_mesh((1, n_model), ("data", "model"), devices[:n_model])
 
 
 def mesh_desc(mesh) -> str:

@@ -9,12 +9,13 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
-from repro.serve import Request, ServeEngine
 from repro.models import init_params, param_specs
-import jax
+from repro.runtime import enable_compile_cache
+from repro.serve import Request, ServeEngine
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--act-impl", default=None,
                     choices=[None, "exact", "ppa", "ppa8"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.act_impl:

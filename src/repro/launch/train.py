@@ -26,7 +26,8 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM
 from repro.models import (ShardCtx, init_params, make_model_acts,
                           param_specs)
-from repro.runtime import MetricsLogger, StepHang, Watchdog
+from repro.runtime import (MetricsLogger, StepHang, Watchdog,
+                           enable_compile_cache)
 from repro.train import OptCfg, ScheduleCfg, TrainCfg, make_train_step, \
     train_init
 
@@ -111,6 +112,7 @@ def main():
     ap.add_argument("--simulate-crash-at", type=int, default=-1)
     ap.add_argument("--metrics", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.act_impl:

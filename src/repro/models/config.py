@@ -70,7 +70,7 @@ class ModelCfg:
     act_backend: str = "ref"  # ref (paper-faithful searchsorted+horner) |
     #                           lut_index (gather index, keep datapath) |
     #                           lut_value (single-gather, bit-exact) |
-    #                           pallas / pallas_interpret (TPU kernel)
+    #                           pallas / pallas_fused (Pallas kernels)
     kv_shard: str = "heads"   # heads (pad kv to TP) | seq (flash-decode)
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"

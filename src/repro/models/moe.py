@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
 
 from .activations import ActBundle
 from .common import P, ShardCtx
@@ -182,7 +181,7 @@ def _moe_sharded(params, x, cfg: MoECfg, acts, ctx: ShardCtx):
 
     fn = functools.partial(_moe_body, cfg=cfg, acts=acts, e_loc=e_loc,
                            dp=dp, tp=tp, batch_sharded=bool(bspec))
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"],

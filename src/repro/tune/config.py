@@ -47,11 +47,9 @@ TUNE_VERSION = 1
 def device_key() -> str:
     """``<accelerator backend>/<device kind>`` for this process — the
     identity tuned configs are addressed by."""
-    try:
-        import jax
-        return f"{jax.default_backend()}/{jax.devices()[0].device_kind}"
-    except Exception:
-        return "none/host"
+    import jax
+
+    return f"{jax.default_backend()}/{jax.devices()[0].device_kind}"
 
 
 @dataclasses.dataclass
@@ -187,14 +185,11 @@ def activate(cfg: TunedConfig) -> Dict[str, object]:
 def activate_for_store(store) -> Optional[TunedConfig]:
     """Resolve + activate the tuned config persisted next to ``store``
     (a ``TableStore``).  Returns the config, or None when the store is
-    memory-only, tuning is disabled, or no config exists for this device.
-    Never raises — serving and sweeps must start with or without one."""
-    try:
-        if not getattr(store, "persist", False):
-            return None
-        cfg = resolve_tuned(store.root)
-        if cfg is not None:
-            activate(cfg)
-        return cfg
-    except Exception:
+    memory-only, tuning is disabled, or no config exists for this device
+    — serving and sweeps start with or without one."""
+    if not store.persist:
         return None
+    cfg = resolve_tuned(store.root)
+    if cfg is not None:
+        activate(cfg)
+    return cfg

@@ -34,9 +34,9 @@ SCHEME16 = PPAScheme(order=2, quantizer="fqa")
 SCHEME8 = PPAScheme(order=1, m_shifters=4, quantizer="fqa")
 
 ZOO = sorted(NAF_REGISTRY)
-# executable on CPU: the pallas kernels run in interpret mode (same body)
-INT_BACKENDS = ["ref", "lut_value", "lut_index", "pallas_interpret"]
-ALL_BACKENDS = INT_BACKENDS + ["pallas_fused_interpret"]
+# executable on CPU: the pallas kernels interpret there (same body)
+INT_BACKENDS = ["ref", "lut_value", "lut_index", "pallas"]
+ALL_BACKENDS = INT_BACKENDS + ["pallas_fused"]
 
 _TABLES = {}
 
@@ -223,7 +223,7 @@ def test_softmax_kernel_round_mults_regression():
     # no-padding shape: rows % block_m == 0, cols == 128, so every float
     # reduction sees identical shapes and the comparison is exact
     x = jnp.asarray(rng.normal(0, 4, size=(16, 128)), jnp.float32)
-    y_k = np.asarray(softmax_ppa_2d(x, tc, interpret=True))
+    y_k = np.asarray(softmax_ppa_2d(x, tc))
     y_w = np.asarray(ppa_softmax(tc, x))
     np.testing.assert_array_equal(y_k, y_w)
 

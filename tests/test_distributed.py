@@ -73,10 +73,35 @@ def test_moe_sharded_matches_local():
 
 
 @pytest.mark.dryrun
+def test_embed_lookup_on_vocab_sharded_local_mesh():
+    """``make_local_mesh`` makes every axis Auto, so the embedding
+    gather on a vocab-sharded table compiles (an Explicit axis makes it a
+    sharding-type error) and matches the unsharded gather."""
+    out = _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as PS
+        from repro.distributed import make_ctx
+        from repro.launch.mesh import make_local_mesh
+        from repro.models.layers import embed_lookup
+
+        mesh = make_local_mesh(4)
+        assert dict(mesh.shape) == {"data": 1, "model": 4}
+        ctx = make_ctx(mesh)
+        table = jax.random.normal(jax.random.PRNGKey(0), (512, 64))
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 512)
+        sharded = jax.device_put(table, NamedSharding(mesh, PS("model")))
+        got = jax.jit(lambda t, x: embed_lookup(t, x, ctx))(sharded, tokens)
+        want = jnp.take(table, tokens, axis=0)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        print("EMBED_OK")
+    """, n_dev=4)
+    assert "EMBED_OK" in out
+
+
+@pytest.mark.dryrun
 def test_ef_allreduce_preserves_sum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import shard_map
         from repro.distributed import ef_allreduce
         from jax.sharding import PartitionSpec as PS
 
@@ -87,7 +112,7 @@ def test_ef_allreduce_preserves_sum():
             mean, new_err = ef_allreduce(gl[0] + err[0], "dp")
             return mean, new_err[None]
 
-        sm = jax.jit(shard_map(
+        sm = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(PS("dp"), PS("dp")),
             out_specs=(PS(), PS("dp")), check_vma=False))
         err = jnp.zeros_like(g)

@@ -1,4 +1,4 @@
-"""Kernel-layer equivalence: numpy golden == jnp ref == Pallas (interpret).
+"""Kernel-layer equivalence: numpy golden == jnp ref == Pallas kernel.
 
 Per the deliverable spec: sweep shapes/dtypes for each Pallas kernel and
 assert_allclose (here: exact integer equality where the datapath is integer,
@@ -79,12 +79,12 @@ def test_pallas_backend_through_ppa_apply(tab8):
     for shape in [(5,), (3, 100), (2, 3, 50)]:
         x = jnp.asarray(rng.uniform(-4, 4, size=shape), dtype=jnp.float32)
         a = ppa_apply(tc, x, backend="ref")
-        b = ppa_apply(tc, x, backend="pallas_interpret")
+        b = ppa_apply(tc, x, backend="pallas")
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("backend",
-                         ["lut_index", "lut_value", "pallas_fused_interpret"])
+                         ["lut_index", "lut_value", "pallas_fused"])
 @pytest.mark.parametrize("which", ["tab8", "tab16"])
 def test_lut_backends_bit_exact(which, backend, request):
     """The beyond-paper LUT/fused deployment modes match the datapath
@@ -173,7 +173,7 @@ def test_softmax_kernel_matches_wrapper(tab_exp2):
     tc = pack_table(tab_exp2)
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.normal(0, 3, size=(10, 200)), dtype=jnp.float32)
-    y_k = np.asarray(softmax_ppa_2d(x, tc, interpret=True))
+    y_k = np.asarray(softmax_ppa_2d(x, tc))
     y_w = np.asarray(ppa_softmax(tc, x))
     np.testing.assert_allclose(y_k, y_w, atol=1e-6)
 
@@ -183,6 +183,6 @@ def test_softmax_kernel_row_padding(tab_exp2):
     tc = pack_table(tab_exp2)
     rng = np.random.default_rng(13)
     x = jnp.asarray(rng.normal(0, 2, size=(5, 130)), dtype=jnp.float32)
-    y = np.asarray(softmax_ppa_2d(x, tc, interpret=True))
+    y = np.asarray(softmax_ppa_2d(x, tc))
     ref = np.asarray(ppa_softmax(tc, x))
     np.testing.assert_allclose(y, ref, atol=1e-6)

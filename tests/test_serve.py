@@ -125,8 +125,8 @@ def test_engine_fused_act_backend_matches_ref():
     rng = np.random.default_rng(3)
     b = reqs()
     fused_eng = ServeEngine(cfg, params, n_slots=2, cache_len=48,
-                            act_backend="pallas_fused_interpret")
-    assert fused_eng.cfg.act_backend == "pallas_fused_interpret"
+                            act_backend="pallas_fused")
+    assert fused_eng.cfg.act_backend == "pallas_fused"
     for r in b:
         fused_eng.submit(r)
     fused_eng.run_until_drained()
