@@ -13,6 +13,11 @@ fixed-point FQA datapath (with straight-through gradients for training).
              variants for the model-range functions)
   "ppa8"   — the 8-bit FQA-S4-O1 deployment point (aggressive, for
              accuracy-degradation studies)
+
+Every callable of a bundle runs under ``jax.named_scope("act.<field>")``
+(``act.silu``, ..., ``act.softmax``) whichever implementation backs it,
+so the ops it lowers to carry that scope in their HLO ``op_name`` and a
+profiler trace can sum their device time by activation.
 """
 
 from __future__ import annotations
@@ -146,14 +151,28 @@ def _ppa_bundle(bits: int, backend: str, store=None) -> ActBundle:
                      exp_decay=exp_decay, softmax=softmax)
 
 
+def _scoped(bundle: ActBundle) -> ActBundle:
+    """``bundle`` with each callable under ``jax.named_scope("act.<field>")``:
+    op metadata only, the program is unchanged."""
+    def wrap(name: str, fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return dataclasses.replace(bundle, **{
+        f.name: wrap(f"act.{f.name}", getattr(bundle, f.name))
+        for f in dataclasses.fields(bundle) if f.name != "impl"})
+
+
 @functools.lru_cache(maxsize=None)
 def _cached_bundle(impl: str, backend: str, store) -> ActBundle:
     if impl == "exact":
-        return _exact_bundle()
+        return _scoped(_exact_bundle())
     if impl in ("ppa", "ppa16"):
-        return _ppa_bundle(16, backend, store)
+        return _scoped(_ppa_bundle(16, backend, store))
     if impl == "ppa8":
-        return _ppa_bundle(8, backend, store)
+        return _scoped(_ppa_bundle(8, backend, store))
     raise ValueError(f"unknown activation impl {impl!r}")
 
 

@@ -28,6 +28,14 @@ Two serving-tier optimisations make the engine multi-caller fast:
   ``jax.random.categorical`` over per-row keys produces the same bits as
   the row-at-a-time calls.
 
+Tracing: ``step()`` writes ``jax.profiler.TraceAnnotation`` host spans
+named ``serve.*`` (reap, admit, prefill, insert_cache, decode, sync,
+bookkeep) with integer arguments, on the profiler's clock, so a trace
+can say what the host was doing while the device idled; the two jitted
+programs are named ``serve_prefill`` and ``serve_decode``.  While no
+trace is active a span does not format its arguments and costs about a
+microsecond of host time (docs/OPERATIONS.md).
+
 Sampling: greedy or temperature.  The PPA activation tables run inside
 both prefill and decode when the model config selects ``act_impl="ppa"``
 — serving *is* the paper's deployment scenario, so the engine resolves
@@ -53,6 +61,8 @@ from repro.models import (ModelCfg, ShardCtx, decode_step, init_cache,
                           make_model_acts, prefill)
 
 __all__ = ["Request", "ServeEngine"]
+
+_span = jax.profiler.TraceAnnotation
 
 #: Smallest prompt-length bucket.  Below this every group shares one
 #: trace; above it buckets double, so distinct padded shapes stay
@@ -124,13 +134,18 @@ class ServeEngine:
         self.slot_req: List[Optional[Request]] = [None] * n_slots
         self.remaining = np.zeros((n_slots,), np.int32)
         self.rng = jax.random.PRNGKey(rng_seed)
-        self._decode = jax.jit(
-            lambda p, c, t, pos: decode_step(p, cfg, c, t, pos, self.acts,
-                                             self.ctx))
-        self._prefill = jax.jit(
-            lambda p, batch, last: prefill(p, cfg, batch, cache_len,
-                                           self.acts, self.ctx,
-                                           last_idx=last))
+
+        # named, so their HLO modules read jit_serve_decode and
+        # jit_serve_prefill in a profiler trace
+        def serve_decode(p, c, t, pos):
+            return decode_step(p, cfg, c, t, pos, self.acts, self.ctx)
+
+        def serve_prefill(p, batch, last):
+            return prefill(p, cfg, batch, cache_len, self.acts, self.ctx,
+                           last_idx=last)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
         self.queue: Deque[Request] = collections.deque()
         # admission-control knobs: a bounded queue sheds (rejects) instead
         # of buffering unboundedly; per-request deadlines are reaped at
@@ -226,50 +241,58 @@ class ServeEngine:
         return b
 
     def _admit(self) -> None:
-        free = self._free_slots()
-        n = min(len(free), len(self.queue))
-        if n == 0:
-            return
-        # FIFO -> slot mapping identical to per-request admission
-        pairs = [(free[j], self.queue.popleft()) for j in range(n)]
-        # pre-split sampling keys in FIFO order: the RNG stream must not
-        # depend on how requests group into prefill micro-batches
-        keys: Dict[int, jax.Array] = {}
-        for _, req in pairs:
-            if req.temperature > 0:
-                self.rng, k = jax.random.split(self.rng)
-                keys[id(req)] = k
-        if not self.coalesce:
+        """Admit queued requests into free slots, under a ``serve.admit``
+        span whose args are the requests admitted (``rows``) and the
+        prefills run for them (``groups``)."""
+        with _span("serve.admit") as span:
+            free = self._free_slots()
+            n = min(len(free), len(self.queue))
+            # FIFO -> slot mapping identical to per-request admission
+            pairs = [(free[j], self.queue.popleft()) for j in range(n)]
+            # pre-split sampling keys in FIFO order: the RNG stream must
+            # not depend on how requests group into prefill micro-batches
+            keys: Dict[int, jax.Array] = {}
+            for _, req in pairs:
+                if req.temperature > 0:
+                    self.rng, k = jax.random.split(self.rng)
+                    keys[id(req)] = k
+            if not self.coalesce:
+                for slot, req in pairs:
+                    self._admit_serial(slot, req, keys.get(id(req)))
+                span.set_metadata(rows=n, groups=n)
+                return
+            groups: Dict[tuple, list] = {}
             for slot, req in pairs:
-                self._admit_serial(slot, req, keys.get(id(req)))
-            return
-        groups: Dict[tuple, list] = {}
-        for slot, req in pairs:
-            sig = (self._bucket_len(len(req.prompt)),
-                   tuple(sorted(req.extra)) if req.extra else ())
-            groups.setdefault(sig, []).append((slot, req))
-        for (blen, _), members in groups.items():
-            self._admit_group(blen, members, keys)
+                sig = (self._bucket_len(len(req.prompt)),
+                       tuple(sorted(req.extra)) if req.extra else ())
+                groups.setdefault(sig, []).append((slot, req))
+            for (blen, _), members in groups.items():
+                self._admit_group(blen, members, keys)
+            span.set_metadata(rows=n, groups=len(groups))
 
     def _admit_serial(self, slot: int, req: Request,
                       key: Optional[jax.Array]) -> None:
         """Batch=1 admission — the serial baseline path (and the exact
         pre-coalescing engine behaviour the tests pin tokens against)."""
-        batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
-        if req.extra:
-            batch.update({k: jnp.asarray(v[None]) for k, v in
-                          req.extra.items()})
-        logits, cache1 = prefill(self.params, self.cfg, batch,
-                                 self.cache_len, self.acts, self.ctx)
-        if key is None:
-            # serial-baseline contract: one sync per admitted request IS
-            # the behaviour the coalesced path is benchmarked against.
-            # analysis: allow(host-sync)
-            tok = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
-        else:
-            # analysis: allow(host-sync) — see above; same contract
-            tok = int(np.asarray(jax.random.categorical(
-                key, logits / req.temperature, axis=-1))[0])
+        lp = len(req.prompt)
+        with _span("serve.prefill", bucket=lp, rows=1, real_tokens=lp,
+                   padded_tokens=lp, rids=(req.rid,)):
+            batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
+            if req.extra:
+                batch.update({k: jnp.asarray(v[None]) for k, v in
+                              req.extra.items()})
+            logits, cache1 = prefill(self.params, self.cfg, batch,
+                                     self.cache_len, self.acts, self.ctx)
+        with _span("serve.sync", rows=1, phase=0):
+            if key is None:
+                # serial-baseline contract: one sync per admitted request
+                # IS the behaviour the coalesced path is benchmarked
+                # against.  analysis: allow(host-sync)
+                tok = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
+            else:
+                # analysis: allow(host-sync) — see above; same contract
+                tok = int(np.asarray(jax.random.categorical(
+                    key, logits / req.temperature, axis=-1))[0])
         self._insert_cache([slot], cache1, [0])
         self._start_slot(slot, req, tok)
 
@@ -278,28 +301,33 @@ class ServeEngine:
         """One batched prefill for every (slot, request) in ``members``,
         padded on the right to the shared ``blen`` token bucket."""
         g = len(members)
-        toks = np.zeros((g, blen), np.int32)
-        last = np.zeros((g,), np.int32)
-        for j, (_, req) in enumerate(members):
-            lp = len(req.prompt)
-            toks[j, :lp] = req.prompt
-            last[j] = self.cfg.vision_tokens + lp - 1
-        batch = {"tokens": jnp.asarray(toks)}
-        extra = members[0][1].extra
-        if extra:
-            for k in extra:
-                batch[k] = jnp.asarray(
-                    np.stack([req.extra[k] for _, req in members]))
-        sig = (blen, g, tuple(sorted(extra)) if extra else ())
-        if sig not in self._prefill_shapes:
-            self._prefill_shapes.add(sig)
-            self.prefill_retraces += 1
-        logits, cache1 = self._prefill(self.params, batch,
-                                       jnp.asarray(last))
-        toks_out = self._sample_rows(
-            logits,
-            [req.temperature for _, req in members],
-            [keys.get(id(req)) for _, req in members])
+        real = sum(len(req.prompt) for _, req in members)
+        with _span("serve.prefill", bucket=blen, rows=g, real_tokens=real,
+                   padded_tokens=blen * g,
+                   rids=tuple(req.rid for _, req in members)):
+            toks = np.zeros((g, blen), np.int32)
+            last = np.zeros((g,), np.int32)
+            for j, (_, req) in enumerate(members):
+                lp = len(req.prompt)
+                toks[j, :lp] = req.prompt
+                last[j] = self.cfg.vision_tokens + lp - 1
+            batch = {"tokens": jnp.asarray(toks)}
+            extra = members[0][1].extra
+            if extra:
+                for k in extra:
+                    batch[k] = jnp.asarray(
+                        np.stack([req.extra[k] for _, req in members]))
+            sig = (blen, g, tuple(sorted(extra)) if extra else ())
+            if sig not in self._prefill_shapes:
+                self._prefill_shapes.add(sig)
+                self.prefill_retraces += 1
+            logits, cache1 = self._prefill(self.params, batch,
+                                           jnp.asarray(last))
+        with _span("serve.sync", rows=g, phase=0):
+            toks_out = self._sample_rows(
+                logits,
+                [req.temperature for _, req in members],
+                [keys.get(id(req)) for _, req in members])
         self._insert_cache([s for s, _ in members], cache1, list(range(g)))
         for j, (slot, req) in enumerate(members):
             self._start_slot(slot, req, int(toks_out[j]))
@@ -319,12 +347,13 @@ class ServeEngine:
         with one batched dynamic update per cache leaf.
 
         Cache leaves have layout (L, B, ...) per stage."""
-        sl = jnp.asarray(np.asarray(slots, np.int32))
-        rw = jnp.asarray(np.asarray(rows, np.int32))
+        with _span("serve.insert_cache", rows=len(slots)):
+            sl = jnp.asarray(np.asarray(slots, np.int32))
+            rw = jnp.asarray(np.asarray(rows, np.int32))
 
-        def ins(full, one):
-            return full.at[:, sl].set(one[:, rw].astype(full.dtype))
-        self.cache = jax.tree_util.tree_map(ins, self.cache, cache1)
+            def ins(full, one):
+                return full.at[:, sl].set(one[:, rw].astype(full.dtype))
+            self.cache = jax.tree_util.tree_map(ins, self.cache, cache1)
 
     # ------------------------------------------------------------ sampling
     def _sample_rows(self, logits: jax.Array, temps: Sequence[float],
@@ -373,41 +402,50 @@ class ServeEngine:
         Returns the number of active sequences stepped."""
         failpoint("serve.decode.step")
         if self._has_deadlines:
-            self._reap_deadlines()
+            with _span("serve.reap") as span:
+                span.set_metadata(n=self._reap_deadlines())
         self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return 0
-        toks = jnp.asarray(self.cur_tok[:, None], jnp.int32)
-        pos = jnp.asarray(self.pos, jnp.int32)
-        logits, self.cache = self._decode(self.params, self.cache, toks, pos)
-        # split keys per active temperature slot, in slot order — the
-        # same stream the per-slot sampling loop consumed
-        temps: List[float] = []
-        keys: List[Optional[jax.Array]] = []
-        for i in active:
-            t = self.slot_req[i].temperature
-            temps.append(t)
-            if t > 0:
-                self.rng, k = jax.random.split(self.rng)
-                keys.append(k)
-            else:
-                keys.append(None)
-        sampled = self._sample_rows(logits[jnp.asarray(active)], temps, keys)
-        nxt = np.zeros((self.n_slots,), np.int32)
-        now = time.perf_counter()
-        for j, i in enumerate(active):
-            req = self.slot_req[i]
-            tok = int(sampled[j])
-            nxt[i] = tok
-            req.output.append(tok)
-            self.pos[i] += 1
-            self.remaining[i] -= 1
-            if self.remaining[i] <= 0:
-                req.done = True
-                req.t_done = now
-                self.slot_req[i] = None
-        self.cur_tok = nxt
+        with _span("serve.decode", active=len(active)):
+            toks = jnp.asarray(self.cur_tok[:, None], jnp.int32)
+            pos = jnp.asarray(self.pos, jnp.int32)
+            logits, self.cache = self._decode(self.params, self.cache, toks,
+                                              pos)
+            # split keys per active temperature slot, in slot order — the
+            # same stream the per-slot sampling loop consumed
+            temps: List[float] = []
+            keys: List[Optional[jax.Array]] = []
+            for i in active:
+                t = self.slot_req[i].temperature
+                temps.append(t)
+                if t > 0:
+                    self.rng, k = jax.random.split(self.rng)
+                    keys.append(k)
+                else:
+                    keys.append(None)
+            rows_logits = logits[jnp.asarray(active)]
+        with _span("serve.sync", rows=len(active), phase=1):
+            sampled = self._sample_rows(rows_logits, temps, keys)
+        with _span("serve.bookkeep") as span:
+            nxt = np.zeros((self.n_slots,), np.int32)
+            now = time.perf_counter()
+            finished = 0
+            for j, i in enumerate(active):
+                req = self.slot_req[i]
+                tok = int(sampled[j])
+                nxt[i] = tok
+                req.output.append(tok)
+                self.pos[i] += 1
+                self.remaining[i] -= 1
+                if self.remaining[i] <= 0:
+                    req.done = True
+                    req.t_done = now
+                    self.slot_req[i] = None
+                    finished += 1
+            self.cur_tok = nxt
+            span.set_metadata(finished=finished)
         return len(active)
 
     # -------------------------------------------------------------- warmup
