@@ -16,9 +16,10 @@ justification, which the CI gate reviews by diff):
 * ``float-int-path`` — float contamination in the designated integer
   golden-path functions (``horner_body``, ``apply_shift``, ``concat_add``,
   ``trunc_shift``, ``ppa_eval_block``, ``select_coeffs_sweep``,
-  ``horner_int``, ``ppa_eval_ref``): true division, ``float()`` casts,
-  float literals, ``*.float32``-family dtypes.  The bit-exactness
-  contract says these bodies are ``* + >> <<`` on integers only.
+  ``select_coeffs_bisect``, ``horner_int``, ``ppa_eval_ref``): true
+  division, ``float()`` casts, float literals, ``*.float32``-family
+  dtypes.  The bit-exactness contract says these bodies are ``* + >> <<``
+  on integers only.
 * ``nondet-iter`` — iteration over unordered producers (``glob``,
   ``iterdir``, ``listdir``, ``set(...)``) without ``sorted(...)`` in the
   store/compile modules, where iteration order can feed
@@ -53,7 +54,8 @@ _FLOAT_DTYPE_RE = re.compile(r"\.(float16|float32|float64|bfloat16)\b")
 #: integer golden-path functions under the float-int-path contract
 GOLDEN_PATH_FUNCTIONS = frozenset({
     "horner_body", "apply_shift", "concat_add", "trunc_shift",
-    "ppa_eval_block", "select_coeffs_sweep", "horner_int", "ppa_eval_ref",
+    "ppa_eval_block", "select_coeffs_sweep", "select_coeffs_bisect",
+    "horner_int", "ppa_eval_ref",
 })
 
 #: hot functions under the host-sync contract, per file suffix

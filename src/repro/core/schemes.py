@@ -88,8 +88,9 @@ class PPATable:
     def validate(self) -> "PPATable":
         """Structural invariants every consumer relies on: one coefficient
         row per segment and *strictly* increasing breakpoint starts — the
-        searchsorted index generator and the kernels' comparator sweep both
-        assume it, for uniform and non-uniform layouts alike."""
+        searchsorted index generator and the kernels' segment select (the
+        comparator sweep or the blocked bisection, chosen by S) all assume
+        it, for uniform and non-uniform layouts alike."""
         s = self.num_segments
         if s == 0:
             raise ValueError(f"table {self.naf}: no segments")

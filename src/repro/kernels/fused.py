@@ -31,7 +31,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan
 
-from .body import ppa_eval_block, resolve_interpret, smem_operands
+from .body import (kernel_name, ppa_eval_block, resolve_interpret,
+                   table_operands)
 from .ppa import DEFAULT_BLOCK, default_block, pad_to_tiles
 
 __all__ = ["ppa_fused_2d", "ppa_fused_apply", "fused_kernel_statics"]
@@ -109,7 +110,7 @@ def ppa_fused_2d(
     m, n = xf.shape
     grid = (m // block[0], n // block[1])
     kernel = functools.partial(_fused_kernel, gate=gate, **statics)
-    table_specs, table_args = smem_operands(starts, coefs)
+    table_specs, table_args = table_operands(starts, coefs)
 
     return pl.pallas_call(
         kernel,
@@ -118,6 +119,7 @@ def ppa_fused_2d(
         out_specs=pl.BlockSpec(block, lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name=kernel_name("ppa_fused", statics["num_segments"]),
     )(xf.astype(jnp.float32), *table_args)
 
 

@@ -111,9 +111,10 @@ class TableConsts:
 def pack_table(table: PPATable) -> TableConsts:
     from repro.core.schemes import eval_table_int
 
-    # breakpoint layout contract: the comparator sweep and the searchsorted
-    # index LUT below both require strictly increasing starts — holds for
-    # uniform and non-uniform segmenters, and guards hand-built tables.
+    # breakpoint layout contract: the kernels' segment selects and the
+    # searchsorted index LUT below require strictly increasing starts —
+    # holds for uniform and non-uniform segmenters, and guards hand-built
+    # tables.
     table.validate()
     spec = get_naf(table.naf)
     coefs = np.concatenate([table.a_int, table.b_int[:, None]], axis=1)

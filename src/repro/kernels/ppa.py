@@ -1,16 +1,18 @@
 """Pallas TPU kernel for batched fixed-point PPA activation evaluation.
 
-The kernel body is the shared one from :mod:`repro.kernels.body` (comparator
-sweep + ``core.datapath.horner_body``); every shift/alignment constant comes
-from a :class:`~repro.core.datapath.DatapathPlan` — this module derives
-nothing on its own.
+The kernel body is the shared one from :mod:`repro.kernels.body` (segment
+select chosen by S + ``core.datapath.horner_body``); every shift/alignment
+constant comes from a :class:`~repro.core.datapath.DatapathPlan` — this
+module derives nothing on its own.
 
 Block layout: x is tiled (block_m, 128) int32 — the minor dimension matches
 the 128-lane VPU; block_m=256 keeps in+out VMEM traffic at 256 KiB/block,
 far below the ~16 MiB v5e VMEM budget, leaving room for double buffering.
-The segment starts and the flattened (S * (n+1),) int32 coefficient ROM sit
-whole in SMEM (< 8 KiB for every deployment table): the comparator sweep
-reads them one scalar at a time.
+The table operands come from :func:`~repro.kernels.body.table_operands`:
+for the comparator sweep the segment starts and the flattened
+(S * (n+1),) int32 coefficient ROM sit whole in SMEM (< 8 KiB for every
+deployment table), read one scalar at a time; for the blocked bisection
+they sit whole in VMEM as 128-lane tiles, read by lane gathers.
 """
 
 from __future__ import annotations
@@ -25,7 +27,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan, FWLConfig
 
-from .body import ppa_eval_block, resolve_interpret, smem_operands
+from .body import (kernel_name, ppa_eval_block, resolve_interpret,
+                   table_operands)
 
 if TYPE_CHECKING:  # avoid a module-level kernels -> core.schemes import edge
     from repro.core.schemes import PPATable
@@ -107,7 +110,7 @@ def ppa_eval_2d(
     s = starts.shape[0]
     grid = (m // block[0], n // block[1])
     kernel = functools.partial(_ppa_kernel, plan=plan, num_segments=s)
-    table_specs, table_args = smem_operands(starts, coefs)
+    table_specs, table_args = table_operands(starts, coefs)
 
     return pl.pallas_call(
         kernel,
@@ -116,6 +119,7 @@ def ppa_eval_2d(
         out_specs=pl.BlockSpec(block, lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=resolve_interpret(interpret),
+        name=kernel_name("ppa_int", s),
     )(x_int.astype(jnp.int32), *table_args)
 
 

@@ -4,7 +4,7 @@ The Horner chain is literally ``core.datapath.horner_body`` (the same code
 object the numpy golden model runs, here under jnp int32), driven by a
 :class:`~repro.core.datapath.DatapathPlan`; only the segment select differs
 from the Pallas kernels (a searchsorted gather instead of the comparator
-sweep).  Bit-identical to kernels/ppa.py and to the numpy golden model
+sweep or the blocked bisection).  Bit-identical to kernels/ppa.py and to the numpy golden model
 (core.schemes.eval_table_int); tests assert exact integer equality among
 all three.
 """

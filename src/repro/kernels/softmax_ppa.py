@@ -8,7 +8,7 @@ Only the fractional power 2**f goes through the fixed-point PPA datapath
 float (exact ldexp / one division per row) — exactly the split a hardware
 softmax unit makes between the NAF core and the float post-scaler.
 
-The integer stage is the shared kernel body (comparator sweep +
+The integer stage is the shared kernel body (segment select chosen by S +
 ``core.datapath.horner_body``) driven by the table's
 :class:`~repro.core.datapath.DatapathPlan` — including the ``round_mults``
 half-ULP add, which a previous hand-rolled copy of the Horner chain here
@@ -33,7 +33,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.datapath import DatapathPlan
 
-from .body import ppa_eval_block, resolve_interpret, smem_operands
+from .body import (kernel_name, ppa_eval_block, resolve_interpret,
+                   table_operands)
 from .ops import TableConsts
 
 __all__ = ["softmax_ppa_2d"]
@@ -83,7 +84,7 @@ def softmax_ppa_2d(x: jax.Array, tc: TableConsts, *, block_m: int = 8,
     kernel = functools.partial(_softmax_kernel, plan=plan,
                                num_segments=tc.num_segments, valid_n=n)
 
-    table_specs, table_args = smem_operands(tc.starts, tc.coefs)
+    table_specs, table_args = table_operands(tc.starts, tc.coefs)
     out = pl.pallas_call(
         kernel,
         grid=(mp // block_m,),
@@ -92,5 +93,6 @@ def softmax_ppa_2d(x: jax.Array, tc: TableConsts, *, block_m: int = 8,
         out_specs=pl.BlockSpec((block_m, np_), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name=kernel_name("ppa_softmax", tc.num_segments),
     )(xp.astype(jnp.float32), *table_args)
     return out[:m, :n].astype(x.dtype)

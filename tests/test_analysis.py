@@ -387,6 +387,26 @@ def test_lint_float_contamination_in_golden_path(tmp_path):
     assert [f.rule for f in found] == ["float-int-path"]
 
 
+@pytest.mark.parametrize("fn", ["select_coeffs_sweep",
+                                "select_coeffs_bisect"])
+def test_lint_segment_selects_are_integer_only(fn, tmp_path):
+    """Both segment selects are under the integer golden-path contract: a
+    float step in either is flagged, and the shipped body is clean."""
+    from repro.analysis.lint import GOLDEN_PATH_FUNCTIONS
+    assert fn in GOLDEN_PATH_FUNCTIONS
+    body = (
+        "import jax.numpy as jnp\n"
+        f"def {fn}(x_int, starts_ref, coef_ref, *, num_segments, order):\n"
+        "    lane = jnp.zeros(x_int.shape, jnp.int32)\n"
+        "    return [lane + num_segments / 2]\n"
+    )
+    p = _lint_fixture(tmp_path, "kernels/body.py", body)
+    assert [f.rule for f in lint_paths([p])] == ["float-int-path"]
+    p.write_text(body.replace("num_segments / 2", "num_segments // 2"))
+    assert lint_paths([p]) == []
+    assert lint_paths([REPO_ROOT / "src/repro/kernels/body.py"]) == []
+
+
 def test_lint_tracer_branch_in_traced_file(tmp_path):
     body = (
         "import jax.numpy as jnp\n"

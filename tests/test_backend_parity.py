@@ -11,7 +11,10 @@ bit-identical.
   kernels used to hand-roll (property test — hypothesis when installed,
   seeded random sweep otherwise);
 * the shared body honors ``round_mults`` in every executor — regression
-  for the softmax kernel that silently dropped the half-ULP add.
+  for the softmax kernel that silently dropped the half-ULP add;
+* both segment selects of the shared body (comparator sweep, blocked
+  bisection) give the same bits on every zoo table, whichever one the
+  table's segment count picks.
 """
 
 import dataclasses
@@ -23,7 +26,7 @@ import pytest
 from repro.compiler import compile_or_load
 from repro.core import (DatapathPlan, FWLConfig, NAF_REGISTRY, PPAScheme,
                         eval_table_int, grid_for_interval)
-from repro.kernels import (available_backends, get_backend, pack_table,
+from repro.kernels import (available_backends, body, get_backend, pack_table,
                            ppa_apply, ppa_eval_ref, ppa_gate, ppa_softmax,
                            register_backend, softmax_ppa_2d)
 
@@ -112,6 +115,41 @@ def test_gated_path_parity(naf, bits, seg):
         got = np.asarray(ppa_gate(tc, x, backend=be))
         np.testing.assert_array_equal(
             got, ref, err_msg=f"gated backend {be} diverges for {naf}")
+
+
+# ----------------------------------------------------- segment select parity
+@pytest.mark.parametrize("bits", [16, 8])
+@pytest.mark.parametrize("naf", ZOO)
+def test_segment_select_parity(naf, bits, monkeypatch):
+    """The int kernel and the fused kernel, gated and ungated, give ``ref``'s
+    bits on the blocked bisection, and the int and gated kernels on the
+    comparator sweep too, at every start, every start - 1, the interval's
+    ends and beyond, both signs — whichever select the table's segment
+    count picks by default.  (The ungated kernel differs from the gated one
+    only after the select; ``tests/test_kernels.py`` runs all three on both
+    selects over synthetic tables, so it is left off the sweep here, where
+    an interpreted 500-segment sweep costs seconds a call.)"""
+    tc = pack_table(_table(naf, bits))
+    st = np.asarray(tc.starts, np.int64)
+    ints = np.concatenate([st, st - 1, [tc.lo, tc.hi - 1, tc.hi, tc.hi + 9]])
+    ints = np.concatenate([ints, -ints])
+    x_int = jnp.asarray(ints, jnp.int32)
+    xf = jnp.asarray(ints / float(1 << tc.w_in), jnp.float32)
+    runs = {
+        "int": lambda be: get_backend(be).eval_int(tc, x_int),
+        "fused": lambda be: ppa_apply(tc, xf, backend=be),
+        "fused_gated": lambda be: ppa_gate(tc, xf, backend=be),
+    }
+    refs = {kernel: np.asarray(run("ref")) for kernel, run in runs.items()}
+    for select, least in (("sweep", 1 << 30), ("bisect", 1)):
+        monkeypatch.setattr(body, "BISECT_MIN_SEGMENTS", least)
+        for kernel, run in runs.items():
+            if select == "sweep" and kernel == "fused":
+                continue
+            be = "pallas" if kernel == "int" else "pallas_fused"
+            np.testing.assert_array_equal(
+                np.asarray(run(be)), refs[kernel],
+                err_msg=f"{kernel} kernel on the {select}, {naf}@{bits}bit")
 
 
 # -------------------------------------------------- plan vs legacy derivation

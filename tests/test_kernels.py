@@ -5,6 +5,7 @@ assert_allclose (here: exact integer equality where the datapath is integer,
 allclose for the float softmax wrapper) against the ref.py oracle.
 """
 
+import dataclasses
 import math
 
 import jax
@@ -18,8 +19,9 @@ from hypothesis import strategies as st
 
 from repro.core import (FWLConfig, PPAScheme, eval_table_int,
                         grid_for_interval, get_table)
-from repro.kernels import (pack_table, ppa_act, ppa_apply, ppa_eval_2d,
-                           ppa_eval_ref, ppa_softmax, softmax_ppa_2d)
+from repro.kernels import (body, get_backend, pack_table, ppa_act, ppa_apply,
+                           ppa_eval_2d, ppa_eval_ref, ppa_fused_apply,
+                           ppa_gate, ppa_softmax, softmax_ppa_2d)
 
 CFG8 = FWLConfig(w_in=8, w_out=8, w_a=(8,), w_o=(8,), w_b=8)
 CFG16 = FWLConfig(w_in=8, w_out=16, w_a=(8, 16), w_o=(16, 16), w_b=16)
@@ -186,3 +188,78 @@ def test_softmax_kernel_row_padding(tab_exp2):
     y = np.asarray(softmax_ppa_2d(x, tc))
     ref = np.asarray(ppa_softmax(tc, x))
     np.testing.assert_allclose(y, ref, atol=1e-6)
+
+
+# ------------------------------------------------------------ segment select
+#: segment counts around the bisection's 128-lane rows, the deployment
+#: gate's 461 and the non-uniform silu@16 table's 516
+SELECT_SEGMENTS = [1, 2, 127, 128, 129, 256, 461, 516]
+
+
+@pytest.fixture(scope="module")
+def tc_wide():
+    return pack_table(get_table("sigmoid_wide", CFG16,
+                                PPAScheme(order=2, quantizer="fqa")))
+
+
+def _synthetic(tc, s: int):
+    """``tc`` with ``s`` strictly increasing random starts in [lo, hi) and
+    its coefficient rows repeated to ``s``."""
+    rng = np.random.default_rng(s)
+    starts = np.sort(rng.choice(np.arange(tc.lo, tc.hi), s, replace=False))
+    coefs = np.resize(np.asarray(tc.coefs), (s, tc.coefs.shape[1]))
+    return dataclasses.replace(tc, num_segments=s,
+                               starts=jnp.asarray(starts, jnp.int32),
+                               coefs=jnp.asarray(coefs, jnp.int32))
+
+
+def _edge_ints(tc) -> np.ndarray:
+    """Every start and the integer below it, the interval's ends and past
+    them, with both signs."""
+    st = np.asarray(tc.starts, np.int64)
+    v = np.concatenate([st, st - 1, [tc.lo, tc.hi - 1, tc.hi, tc.hi + 37]])
+    return np.concatenate([v, -v])
+
+
+def _on(select: str, monkeypatch, fn):
+    """``fn()`` with every kernel traced on the given segment select."""
+    monkeypatch.setattr(body, "BISECT_MIN_SEGMENTS",
+                        1 if select == "bisect" else 1 << 30)
+    return np.asarray(fn())
+
+
+@pytest.mark.parametrize("kernel", ["int", "fused", "fused_gated"])
+@pytest.mark.parametrize("s", SELECT_SEGMENTS)
+def test_select_paths_bit_exact(s, kernel, tc_wide, monkeypatch):
+    """The bisection and the sweep select the same row as ``ref`` for every
+    start, every start - 1, the interval's ends and beyond, both signs."""
+    tc = _synthetic(tc_wide, s)
+    ints = _edge_ints(tc)
+    if kernel == "int":
+        x = jnp.asarray(np.concatenate(
+            [ints, [np.iinfo(np.int32).min, np.iinfo(np.int32).max]]),
+            jnp.int32)
+        run = lambda be: get_backend(be).eval_int(tc, x)
+    else:
+        op = ppa_gate if kernel == "fused_gated" else ppa_apply
+        x = jnp.asarray(ints / float(1 << tc.w_in), jnp.float32)
+        run = lambda be: op(tc, x, backend=be)
+    be = "pallas" if kernel == "int" else "pallas_fused"
+    ref = np.asarray(run("ref"))
+    for select in ("bisect", "sweep"):
+        np.testing.assert_array_equal(
+            _on(select, monkeypatch, lambda: run(be)), ref,
+            err_msg=f"{kernel} kernel on the {select}, S={s}")
+
+
+def test_select_follows_segment_count(tab_exp2, tc_wide):
+    """The select is chosen from the table's segment count alone: the
+    14-segment exp2 table stays on the sweep, the 461-segment gate table
+    bisects, and each kernel is named for the select it compiled with."""
+    x = jnp.zeros((8, 128), jnp.float32)
+    for tc, select in [(pack_table(tab_exp2), "sweep"), (tc_wide, "bisect")]:
+        assert body.uses_bisection(tc.num_segments) == (select == "bisect")
+        jaxpr = str(jax.make_jaxpr(lambda v: ppa_fused_apply(tc, v))(x))
+        assert f"ppa_fused_{select}" in jaxpr, tc.num_segments
+    assert (pack_table(tab_exp2).num_segments, tc_wide.num_segments) == \
+        (14, 461)

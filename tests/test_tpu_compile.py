@@ -7,7 +7,9 @@ The topology is described inside a fixture, so collecting this file never
 loads the TPU library.
 """
 
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +30,10 @@ V5E_HBM_BYTES = 16 * 10**9
 
 #: prefill MLP gate of internlm2-1.8b at 4 requests x 64 tokens
 GATE_SHAPE = (4 * 64, 8192)
+
+#: the same gate in the benchmark's chat cell: one 1024-token prefill,
+#: flattened to the kernel's (rows, 128) tiles
+CHAT_GATE_SHAPE = (1024 * 8192 // 128, 128)
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +83,19 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _mosaic_text(lowered) -> str:
+    """The Mosaic modules of a lowered program's kernels, as MLIR text (a
+    kernel's backend config carries its module as base64 bytecode)."""
+    from jax.extend.mlir import ir
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    bodies = re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)",
+                        lowered.as_text())
+    assert bodies, "no Mosaic kernel in the lowered program"
+    return "\n".join(str(ir.Module.parse(base64.b64decode(b), context=ctx))
+                     for b in bodies)
+
+
 @pytest.mark.parametrize("naf,gate,min_segments", [
     ("sigmoid_wide", True, 400),    # the silu gate of the prefill MLP
     ("exp_neg", False, 400),
@@ -92,6 +111,29 @@ def test_fused_kernel_compiles(naf, gate, min_segments, one_chip,
     assert _has_kernel(compiled)
     assert compiled.memory_analysis().output_size_in_bytes == \
         GATE_SHAPE[0] * GATE_SHAPE[1] * 4
+
+
+@pytest.mark.parametrize("naf,shape,select", [
+    ("sigmoid_wide", GATE_SHAPE, "bisect"),
+    ("sigmoid_wide", CHAT_GATE_SHAPE, "bisect"),
+    ("exp2_frac", GATE_SHAPE, "sweep"),     # the softmax's 14 segments
+])
+def test_fused_kernel_select_compiles(naf, shape, select, one_chip,
+                                      no_persistent_cache):
+    """The 461-segment gate compiles on the blocked bisection, whose lane
+    gathers Mosaic lowers to ``tpu.dynamic_gather``; the 14-segment exp2
+    table compiles on the comparator sweep, with no gather."""
+    tc = _table(naf)
+    gate = naf == "sigmoid_wide"
+    fn = jax.jit(lambda x, s, c: ppa_fused_2d(
+        x, s, c, gate=gate, interpret=False, **fused_kernel_statics(tc)))
+    lowered = fn.lower(_sds(shape, jnp.float32, one_chip),
+                       *_table_args(tc, one_chip))
+    compiled = lowered.compile()
+    assert _has_kernel(compiled)
+    assert f"ppa_fused_{select}" in compiled.as_text()
+    gathers = _mosaic_text(lowered).count("tpu.dynamic_gather")
+    assert (gathers > 0) == (select == "bisect"), gathers
 
 
 def test_int_kernel_compiles(one_chip, no_persistent_cache):
