@@ -1,11 +1,14 @@
 """Run one cell of ``BENCHMARK.json`` once, on the chip.
 
 Everything a cell needs is found by name from files: its configuration
-in ``configs/<config>.json`` (with the plain reference it names, in
-``reference/<name>.py``), its traffic in ``traffic/<mix>.json``, the
-limits of its correctness check in ``limits/<workload>.json``, and each
-per-layer metric's reader in ``layer_metrics/<metric>.py``.  A later cell,
-mix or metric is added by adding files.
+in ``configs/<config>.json``, with the program module it names in
+``programs/<program>.py`` (how the program is built from the file, its
+weight rules beyond the dense ones, its work counts) and the plain
+reference it names in ``reference/<name>.py``; its traffic in
+``traffic/<mix>.json``, the limits of its correctness check in
+``limits/<workload>.json``, and each per-layer metric's reader in
+``layer_metrics/<metric>.py``.  A later configuration of any family,
+cell, mix or metric is added by adding files.
 
 A run: set-up (tables, weights, engine, warm-up of every shape the mix
 can cause), one measured window of ``--seconds`` driving
@@ -51,6 +54,9 @@ def _load_module(path: Path, name: str):
         raise FileNotFoundError(f"no {path}")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    # registered first, as an import would, so that a dataclass in it
+    # can resolve its annotations
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -81,6 +87,35 @@ def load_reference(bench_dir: Path, name: str):
                         f"reference_{name}")
 
 
+def load_program(bench_dir: Path, conf: dict):
+    """The program module that configuration ``conf`` names under
+    ``"program"``: ``programs/<program>.py``, with ``program_cfg(conf)``
+    (the program's ModelCfg), ``program_params(cfg, seed)`` (its
+    parameter tree), ``counts(conf)`` (an object with ``prefill_flops``,
+    ``decode_flops`` and ``decode_step_bytes``) and, optionally,
+    ``ROLES`` (weight rules beyond the dense roles)."""
+    from . import weights
+
+    where = f"configs/{conf.get('name')}.json"
+    if "program" not in conf:
+        raise KeyError(f"{where} names no program: give \"program\": "
+                       f"\"<module>\" for {bench_dir}/programs/<module>.py")
+    path = bench_dir / "programs" / f"{conf['program']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{where} names program "
+                                f"{conf['program']!r}, and there is no {path}")
+    mod = _load_module(path, f"program_{conf['program']}")
+    weights.check_extra(getattr(mod, "ROLES", {}))
+    return mod
+
+
+def weight_rules(bench_dir: Path, conf: dict) -> dict:
+    """The weight rules beyond the dense roles of the program that
+    ``conf`` names (``ROLES`` of its module), for a reference to draw the
+    program's bits."""
+    return getattr(load_program(bench_dir, conf), "ROLES", {})
+
+
 def cell_metrics(spec: dict, workload: str, section: str) -> List[dict]:
     """The metrics of ``section`` that cell ``workload`` reports."""
     return [m for m in spec[section]
@@ -103,7 +138,7 @@ class Step:
 class Run:
     """What a per-layer reader gets."""
 
-    dims: yardstick.Dims
+    counts: yardstick.Counts
     peaks: dict
     window_s: float
     steps: List[Step]
@@ -412,6 +447,7 @@ class Cell:
         self.mix = load_mix(bench_dir, wl["traffic"])
         self.limits = load_limits(bench_dir, wl["name"])
         self.ref_mod = load_reference(bench_dir, self.conf["reference"])
+        self.program = load_program(bench_dir, self.conf)
 
         import jax
         jax.config.update("jax_compilation_cache_dir",
@@ -425,16 +461,14 @@ class Cell:
         self.peaks = yardstick.peaks(kind if require_tpu else "TPU v5e")
 
         from repro.serve import ServeEngine
-        from .model import program_cfg, program_params
         self.counter = _CompileCounter()
-        self._draw = program_params
-        self.cfg = program_cfg(self.conf)
-        self.dims = yardstick.Dims.from_config(self.conf)
+        self.cfg = self.program.program_cfg(self.conf)
+        self.counts = self.program.counts(self.conf)
         t = clock()
         store = _tables(self.cfg.act_impl, bench_dir / ".tables")
         log(f"set-up: tables {clock() - t:.2f} s")
         t = clock()
-        params = program_params(self.cfg, seed)
+        params = self.program.program_params(self.cfg, seed)
         jax.block_until_ready(params)
         log(f"set-up: weights {clock() - t:.2f} s")
         t = clock()
@@ -456,7 +490,7 @@ class Cell:
         seeds in one process)."""
         import jax
         self.eng.params = None
-        self.eng.params = self._draw(self.cfg, seed)
+        self.eng.params = self.program.program_params(self.cfg, seed)
         jax.block_until_ready(self.eng.params)
 
     def measure(self, seed: int, seconds: float,
@@ -596,7 +630,7 @@ def run(argv=None, *, t_start: Optional[float] = None, **cell_kw) -> dict:
 
     metrics = {}
     if args.trace:
-        rec = Run(cell.dims, cell.peaks, t_end - w.t0, w.steps,
+        rec = Run(cell.counts, cell.peaks, t_end - w.t0, w.steps,
                   w.traced_from, summary)
         for m in wanted:
             v = readers[m["name"]](rec)

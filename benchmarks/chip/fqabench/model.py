@@ -1,11 +1,11 @@
-"""The system under test, built from a configuration file.
+"""The program's parameter tree, drawn by role from the seed.
 
-This module and :mod:`fqabench.harness` are the only places that import
-the program (``repro``).  The program's own configuration of the named
-architecture supplies everything the file does not state (attention
-path, remat, and the activation backend unless the file pins one), so a
-change to those defaults is measured; every size the file states
-overrides it.
+Every program module (``programs/<name>.py``) may draw its parameters
+here: the program states its parameter specs, and each leaf is filled by
+:func:`fqabench.weights.draw`, keyed by the leaf's role and by its layer
+counted over the whole model (the stages ``s0_*``, ``s1_*``, ... of the
+program's tree follow one another), so a reference that draws one layer
+at a time gets the same bits.
 """
 
 from __future__ import annotations
@@ -16,32 +16,19 @@ import jax.numpy as jnp
 from . import weights
 
 
-def program_cfg(conf: dict):
-    """``repro`` ModelCfg of a dense GQA decoder as ``conf`` states it."""
-    from repro.configs import get_config
-    from repro.models import StageCfg
-
-    base = get_config(conf["program_arch"])
-    if base.family != "dense" or any(s.kind != "dec" or s.moe or s.window
-                                     for s in base.stages):
-        raise ValueError(f"{conf['program_arch']}: not a dense decoder")
-    return base.replace(
-        arch=conf["name"],
-        d_model=conf["hidden_size"], n_q=conf["num_attention_heads"],
-        n_kv=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-        stages=(StageCfg("dec", conf["num_hidden_layers"]),),
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        act_impl=conf["act_impl"],
-        act_backend=conf.get("act_backend", base.act_backend),
-        param_dtype=conf["torch_dtype"],
-        compute_dtype=conf["torch_dtype"])
+def _first_layer(names, stages) -> int:
+    """The model-wide index of the first layer of the stage that holds a
+    leaf (0 for a leaf outside the stages)."""
+    if len(names) < 2 or names[0] != "stages":
+        return 0
+    i = int(names[1][1:].split("_")[0])
+    return sum(st.n_layers for st in stages[:i])
 
 
-def program_params(cfg, seed: int):
+def draw_params(cfg, seed: int, extra=None):
     """Every parameter of ``cfg``, drawn from ``seed`` in one jitted
-    program on the default device, in the served dtype."""
+    program on the default device, in the served dtype; ``extra`` is the
+    program module's ``ROLES``, the rules of roles beyond the dense ones."""
     from repro.models import param_specs
     from repro.models.common import P
 
@@ -53,14 +40,16 @@ def program_params(cfg, seed: int):
     def build(key):
         leaves = []
         for path, spec in flat:
+            names = [str(getattr(p, "key", p)) for p in path]
             role = weights.role_of(path)
             if spec.axes and spec.axes[0] == "layers":
+                first = _first_layer(names, cfg.stages)
                 inner = spec.shape[1:]
                 w = jax.vmap(lambda l, r=role, s=inner:
-                             weights.draw(key, r, l, s))(
+                             weights.draw(key, r, first + l, s, extra))(
                     jnp.arange(spec.shape[0]))
             else:
-                w = weights.draw(key, role, 0, spec.shape)
+                w = weights.draw(key, role, 0, spec.shape, extra)
             leaves.append(w.astype(dtype))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -1,4 +1,5 @@
-"""The yardstick: chip peaks, and the work a dense GQA decoder needs.
+"""The yardstick: chip peaks, the work a dense GQA decoder needs, and
+the whole step's share of the peak from any program's work counts.
 
 Everything here is computed from shapes and token counts, never from the
 program under test, so a change to the program cannot move it.
@@ -7,7 +8,7 @@ program under test, so a change to the program cannot move it.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, Optional
+from typing import Iterable, Optional, Protocol
 
 #: Published peaks of one chip, keyed by ``jax.Device.device_kind``.
 #: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
@@ -33,10 +34,28 @@ def peaks(device_kind: str) -> dict:
                        f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
+class Counts(Protocol):
+    """The work of a program's steps, from shapes and token counts: what
+    a program module's ``counts(conf)`` returns, and what
+    :func:`traced_mfu` and the per-layer readers call."""
+
+    def prefill_flops(self, prompt_len: int) -> float:
+        """Model FLOPs to prefill one prompt of real (unpadded) length."""
+
+    def decode_flops(self, keys: int) -> float:
+        """Model FLOPs of one decoded token that attends to ``keys``
+        positions (its own included)."""
+
+    def decode_step_bytes(self, keys: Iterable[int]) -> float:
+        """HBM bytes one decode step needs, with one sequence for each
+        entry of ``keys`` (the positions it attends)."""
+
+
 @dataclasses.dataclass(frozen=True)
 class Dims:
-    """Shapes of a dense decoder with grouped-query attention and a
-    SwiGLU MLP, as its configuration file states them."""
+    """The counts of program ``dense_gqa``: shapes of a dense decoder
+    with grouped-query attention and a SwiGLU MLP, as its configuration
+    file states them."""
 
     d_model: int
     n_q: int
@@ -74,54 +93,61 @@ class Dims:
         """One vocab x d_model table (embedding or untied LM head)."""
         return self.vocab * self.d_model
 
+    # ------------------------------------------------------------- work
+    def prefill_flops(self, prompt_len: int) -> float:
+        """Model FLOPs to prefill one prompt of real (unpadded) length:
+        2 per weight per token, causal attention at 4·layers·n_q·head_dim
+        per query-key pair, and the LM head once, for the last token."""
+        t = prompt_len
+        return (2.0 * self.nonembedding_params * t
+                + 4.0 * self.layers * self.n_q * self.head_dim
+                * t * (t + 1) / 2
+                + 2.0 * self.table_params)
 
-def prefill_flops(m: Dims, prompt_len: int) -> float:
-    """Model FLOPs to prefill one prompt of real (unpadded) length:
-    2 per weight per token, causal attention at 4·layers·n_q·head_dim per
-    query-key pair, and the LM head once, for the last token."""
-    t = prompt_len
-    return (2.0 * m.nonembedding_params * t
-            + 4.0 * m.layers * m.n_q * m.head_dim * t * (t + 1) / 2
-            + 2.0 * m.table_params)
+    def decode_flops(self, keys: int) -> float:
+        """Model FLOPs of one decoded token that attends to ``keys``
+        positions (its own included)."""
+        return (2.0 * self.nonembedding_params
+                + 4.0 * self.layers * self.n_q * self.head_dim * keys
+                + 2.0 * self.table_params)
+
+    def kv_bytes_per_token(self, cache_bytes: int = 2) -> int:
+        """K and V of one position across all layers."""
+        return self.layers * 2 * self.n_kv * self.head_dim * cache_bytes
+
+    def weight_bytes(self, weight_bytes_each: int = 2) -> int:
+        """Weights a decode step reads once: every layer, the final norm
+        and the LM head (the embedding contributes only its gathered
+        rows)."""
+        return weight_bytes_each * (self.nonembedding_params
+                                    + self.norm_params + self.table_params)
+
+    def decode_step_bytes(self, keys: Iterable[int],
+                          weight_bytes_each: int = 2,
+                          cache_bytes: int = 2) -> float:
+        """HBM bytes one decode step needs: the weights once, one
+        embedding row per sequence, and the K/V of each sequence's live
+        context."""
+        keys = list(keys)
+        return (self.weight_bytes(weight_bytes_each)
+                + len(keys) * self.d_model * weight_bytes_each
+                + sum(keys) * self.kv_bytes_per_token(cache_bytes))
 
 
-def decode_flops(m: Dims, keys: int) -> float:
-    """Model FLOPs of one decoded token that attends to ``keys``
-    positions (its own included)."""
-    return (2.0 * m.nonembedding_params
-            + 4.0 * m.layers * m.n_q * m.head_dim * keys
-            + 2.0 * m.table_params)
+# the dense counts, as functions of a Dims
+prefill_flops = Dims.prefill_flops
+decode_flops = Dims.decode_flops
+kv_bytes_per_token = Dims.kv_bytes_per_token
+weight_bytes = Dims.weight_bytes
+decode_step_bytes = Dims.decode_step_bytes
 
 
-def kv_bytes_per_token(m: Dims, cache_bytes: int = 2) -> int:
-    """K and V of one position across all layers."""
-    return m.layers * 2 * m.n_kv * m.head_dim * cache_bytes
-
-
-def weight_bytes(m: Dims, weight_bytes_each: int = 2) -> int:
-    """Weights a decode step reads once: every layer, the final norm and
-    the LM head (the embedding contributes only its gathered rows)."""
-    return weight_bytes_each * (m.nonembedding_params + m.norm_params
-                                + m.table_params)
-
-
-def decode_step_bytes(m: Dims, keys: Iterable[int],
-                      weight_bytes_each: int = 2,
-                      cache_bytes: int = 2) -> float:
-    """HBM bytes one decode step needs: the weights once, one embedding
-    row per sequence, and the K/V of each sequence's live context."""
-    keys = list(keys)
-    return (weight_bytes(m, weight_bytes_each)
-            + len(keys) * m.d_model * weight_bytes_each
-            + sum(keys) * kv_bytes_per_token(m, cache_bytes))
-
-
-def step_flops(m: Dims, prefill_lens: Iterable[int],
+def step_flops(counts: Counts, prefill_lens: Iterable[int],
                decode_keys: Iterable[int]) -> float:
     """Model FLOPs of one engine step: the prompts it prefilled and the
     tokens it decoded (each given by the keys it attended)."""
-    return (sum(prefill_flops(m, t) for t in prefill_lens)
-            + sum(decode_flops(m, k) for k in decode_keys))
+    return (sum(counts.prefill_flops(t) for t in prefill_lens)
+            + sum(counts.decode_flops(k) for k in decode_keys))
 
 
 def traced_mfu(run) -> Optional[float]:
@@ -131,7 +157,7 @@ def traced_mfu(run) -> Optional[float]:
     steps = run.traced_steps()
     if not steps:
         return None
-    flops = sum(step_flops(run.dims, s.prefill_lens, s.decode_keys)
+    flops = sum(step_flops(run.counts, s.prefill_lens, s.decode_keys)
                 for s in steps)
     window = run.window_s - run.traced_from
     return 100.0 * flops / (window * run.peaks["bf16_flops_per_s"])

@@ -3,7 +3,9 @@ attention, RoPE, RMSNorm and a SwiGLU MLP (internlm2, mistral-nemo).
 
 It imports nothing of the program.  Its weights are drawn from the seed
 by :mod:`fqabench.weights`, one layer at a time, in the served dtype and
-then widened to float32.  Every matmul runs at ``highest`` precision, and
+then widened to float32, by the rules of the program module that the
+configuration names (``programs/<program>.py``), so both draw the same
+bits.  Every matmul runs at ``highest`` precision, and
 the nonlinearities are the exact functions (exp in the softmax, sigmoid
 in the SwiGLU gate): the program's 16-bit FQA tables approximate these,
 and their error is part of what the comparison sees.
@@ -19,14 +21,16 @@ arithmetic, so it needs no int8 matmul on the device.
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fqabench import weights
+from fqabench import harness, weights
 
+BENCH_DIR = Path(__file__).resolve().parents[1]
 HI = jax.lax.Precision.HIGHEST
 #: served tokens whose logits go through the LM head at once
 HEAD_ROWS = 256
@@ -87,6 +91,7 @@ class Reference:
     def __init__(self, conf: dict, seed: int):
         self.c = conf
         self.key = weights.base_key(seed)
+        self.rules = harness.weight_rules(BENCH_DIR, conf)
         self.dtype = jnp.dtype(conf["torch_dtype"])
         self.eps = float(conf["rms_norm_eps"])
         self.theta = float(conf["rope_theta"])
@@ -96,13 +101,14 @@ class Reference:
     # ------------------------------------------------------------ weights
     @functools.partial(jax.jit, static_argnums=0)
     def _layer_weights(self, key, layer):
-        return {r: _served(weights.draw(key, r, layer, s), self.dtype)
+        return {r: _served(weights.draw(key, r, layer, s, self.rules),
+                           self.dtype)
                 for r, s in self.shapes.items()}
 
     @functools.partial(jax.jit, static_argnums=(0, 2))
     def _table(self, key, role):
-        return _served(weights.draw(key, role, 0, self.table_shape),
-                       self.dtype)
+        return _served(weights.draw(key, role, 0, self.table_shape,
+                                    self.rules), self.dtype)
 
     # -------------------------------------------------------------- layers
     def _attend(self, q, k, v):
@@ -136,7 +142,8 @@ class Reference:
     @functools.partial(jax.jit, static_argnums=0)
     def _final(self, h, key):
         w = _served(weights.draw(key, "ln_f/scale", 0,
-                                 (self.c["hidden_size"],)), self.dtype)
+                                 (self.c["hidden_size"],), self.rules),
+                    self.dtype)
         return _rmsnorm(h, w, self.eps)
 
     def hidden(self, tokens: np.ndarray, mode: str = "float32") -> jax.Array:

@@ -39,10 +39,10 @@ def small_root(tmp_path_factory):
 
 def make_small_root(root: Path) -> Path:
     """A checkout at ``root`` holding one small cell, ``small.small``: its
-    own BENCHMARK.json, config, mix and limits, and the real reference
-    and per-layer readers."""
+    own BENCHMARK.json, config, mix and limits, and the real program
+    modules, reference and per-layer readers."""
     bd = root / "benchmarks" / "chip"
-    for sub in ("reference", "layer_metrics"):
+    for sub in ("programs", "reference", "layer_metrics"):
         shutil.copytree(BENCH / sub, bd / sub,
                         ignore=shutil.ignore_patterns("__pycache__"))
     for sub in ("configs", "traffic", "limits"):

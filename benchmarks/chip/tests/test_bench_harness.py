@@ -71,6 +71,9 @@ def test_benchmark_json_names_files_that_exist():
         assert conf["source"] == c["source"]
         assert sorted(conf["reduced"]) == sorted(c["reduced"])
         assert (BENCH / "reference" / f"{conf['reference']}.py").is_file()
+        program = harness.load_program(BENCH, conf)
+        assert all(callable(getattr(program, f)) for f in
+                   ("program_cfg", "program_params", "counts"))
     for w in SPEC["workloads"]:
         assert w["config"] in configs
         harness.load_mix(BENCH, w["traffic"])

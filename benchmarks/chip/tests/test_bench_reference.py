@@ -9,7 +9,6 @@ import jax
 import numpy as np
 import pytest
 
-from fqabench.model import program_cfg, program_params
 from fqabench import harness
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -49,8 +48,9 @@ def engine_logits(conf, seed, prompts, max_new):
             seen.append(np.asarray(logits, np.float32)[0])
             return super()._sample_rows(logits, temps, keys)
 
-    cfg = program_cfg(conf)
-    eng = Recording(cfg, program_params(cfg, seed), n_slots=1,
+    program = harness.load_program(BENCH, conf)
+    cfg = program.program_cfg(conf)
+    eng = Recording(cfg, program.program_params(cfg, seed), n_slots=1,
                     cache_len=64)
     served, rows = [], []
     for i, p in enumerate(prompts):

@@ -30,7 +30,6 @@ def main(argv):
     from jax.sharding import SingleDeviceSharding
 
     from fqabench import harness
-    from fqabench.model import program_cfg
     from repro.models import (ShardCtx, decode_step, init_cache,
                               make_model_acts, param_specs, prefill)
     from repro.models.common import abstract_params
@@ -39,7 +38,7 @@ def main(argv):
     conf = harness.load_config(HERE, argv[0])
     mix = harness.load_mix(HERE, argv[1])
     slots = [int(a) for a in argv[2:]] or [mix.n_slots]
-    cfg = program_cfg(conf)
+    cfg = harness.load_program(HERE, conf).program_cfg(conf)
     store = harness._tables(cfg.act_impl, HERE / ".tables")
     acts = make_model_acts(cfg, store)
     topo = topologies.get_topology_desc(platform="tpu",
